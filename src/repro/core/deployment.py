@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..parallel.sharding import data_mesh
 from .faults import FaultPlan
 from .store import TableSpec
 
@@ -69,16 +70,6 @@ def fan_in_ratio(n_clients: int, n_db: int) -> int:
     ``ComponentPlan.fan_in`` consult; floors at 1 when clients < shards.
     """
     return max(1, -(-int(n_clients) // max(1, int(n_db))))
-
-
-# jax.device_put grew buffer donation in 0.4.31; staging works (one extra
-# copy alive) without it, so feature-detect instead of pinning a version.
-try:
-    import inspect as _inspect
-    _DEVICE_PUT_DONATE = "donate" in _inspect.signature(
-        jax.device_put).parameters
-except Exception:  # pragma: no cover - signature introspection only
-    _DEVICE_PUT_DONATE = False
 
 
 def split_devices(devices=None, db_fraction: float = 0.25):
@@ -313,10 +304,8 @@ class Clustered(Deployment):
         fault-injected restage re-collects from the original carry)."""
         meta = NamedSharding(self.db_mesh, P())
         vsh = NamedSharding(self.db_mesh, P(None, *self._elem_spec_for(spec)))
-        if donate and _DEVICE_PUT_DONATE:
-            return jax.device_put((keys, values, mask), (meta, vsh, meta),
-                                  donate=True)
-        return jax.device_put((keys, values, mask), (meta, vsh, meta))
+        return jax.device_put((keys, values, mask), (meta, vsh, meta),
+                              donate=donate)
 
     def stage_to_clients(self, x):
         """The read-side hop: a gathered batch (any pytree) leaves the db
@@ -377,7 +366,7 @@ def make_colocated_1d(axis: str = "data", mesh: Mesh | None = None,
                       faults: FaultPlan | None = None) -> Colocated:
     """Convenience: co-located deployment sharding element dim 0 over `axis`."""
     if mesh is None:
-        mesh = jax.make_mesh((len(jax.devices()),), (axis,))
+        mesh = data_mesh(axis=axis)
     spec = [None] * ndim
     spec[shard_dim] = axis
     return Colocated(mesh=mesh, elem_spec=P(*spec), faults=faults)

@@ -908,7 +908,7 @@ def make_clustered_gather(spec: TableSpec, n: int, db_mesh=None,
     Returns a jitted ``fn(state, rng) -> (values [n,*shape], ok)``.
     """
     if shards > 1:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         specs = TableState(slab=P(axis), keys=P(), version=P(),
                            ptr=P(), count=P())
@@ -921,7 +921,7 @@ def make_clustered_gather(spec: TableSpec, n: int, db_mesh=None,
         return jax.jit(shard_map(sharded_body, mesh=db_mesh,
                                  in_specs=(specs, P()),
                                  out_specs=(P(), P()),
-                                 check_rep=False))
+                                 check_vma=False))
 
     def body(state, rng):
         vals, _, ok = sample_impl(spec, state, rng, n, mode)

@@ -16,9 +16,10 @@ classic MXU-tiled matmul:
   VMEM footprint = bm·bk (F) + bk·bn (G) + bm·bn (acc) floats
   = (128·512 + 512·128 + 128·128)·4B ≈ 0.6 MB ≪ 16 MB v5e VMEM,
   leaving room for double buffering of the streamed G tiles.
-* ``w`` is pre-expanded to the flattened I·C axis by the ops wrapper (a
-  [bk] vector per K tile, broadcast-multiplied into the F tile on load —
-  one VPU multiply per element, free next to the MXU work).
+* ``w`` is pre-expanded to the flattened I·C axis by the ops wrapper and
+  passed as a ``[1, K]`` row (a ``[1, bk]`` block per K tile — a legal TPU
+  block whose layout matches XLA's), broadcast-multiplied into the F tile
+  on load — one VPU multiply per element, free next to the MXU work.
 
 On CPU the kernel runs under ``interpret=True`` (tests); ``ops.py`` picks
 the execution mode.
@@ -44,7 +45,7 @@ def _kernel(f_ref, w_ref, g_ref, out_ref, acc_ref, *, n_k: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    f_blk = f_ref[...].astype(jnp.float32) * w_ref[...].astype(jnp.float32)[None, :]
+    f_blk = f_ref[...].astype(jnp.float32) * w_ref[...].astype(jnp.float32)
     g_blk = g_ref[...].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
         f_blk, g_blk, (((1,), (0,)), ((), ())),
@@ -64,14 +65,14 @@ def quadconv_matmul(fm: jax.Array, wk: jax.Array, gm: jax.Array,
 
     Args:
       fm: [M, K]  flattened features (M = batch, K = I·C).
-      wk: [K]     quadrature weights pre-broadcast to the K axis.
+      wk: [1, K]  quadrature weights pre-broadcast to the K axis.
       gm: [K, N]  flattened kernel tensor (N = J·O).
     Returns:
       [M, N] = (fm ⊙ wk) @ gm
     """
     m, k = fm.shape
     k2, n = gm.shape
-    assert k == k2 and wk.shape == (k,), (fm.shape, wk.shape, gm.shape)
+    assert k == k2 and wk.shape == (1, k), (fm.shape, wk.shape, gm.shape)
     bm_, bn_, bk_ = min(bm, m), min(bn, n), min(bk, k)
     if m % bm_ or n % bn_ or k % bk_:
         raise ValueError(
@@ -84,11 +85,13 @@ def quadconv_matmul(fm: jax.Array, wk: jax.Array, gm: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk_,), lambda i, j, kk: (kk,)),
+            pl.BlockSpec((1, bk_), lambda i, j, kk: (0, kk)),
             pl.BlockSpec((bk_, bn_), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), fm.dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(fm, wk, gm)

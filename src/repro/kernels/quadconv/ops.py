@@ -13,11 +13,11 @@ dispatching to:
 
 The wrapper performs the layout work the kernel expects:
   f [B,I,C]   -> fm [B, I·C]           (row-major flatten)
-  w [I]       -> wk [I·C]              (repeat each weight C times)
+  w [I]       -> wk [1, I·C]           (repeat each weight C times)
   g [J,I,O,C] -> gm [I·C, J·O]         (transpose to (I,C,J,O), flatten)
 and pads every GEMM dim up to the block size (zero padding is exact for a
-sum contraction).  A custom VJP reuses the same GEMM for both gradient
-contractions, so the backward pass also hits the MXU kernel on TPU.
+sum contraction).  The custom VJP computes the three gradient
+contractions as XLA einsums: only the forward pass runs the kernel.
 """
 
 from __future__ import annotations
@@ -52,7 +52,7 @@ def _contract_gemm(fm, wk, gm, mode, bm, bn, bk):
     n = gm.shape[1]
     bm_, bn_, bk_ = min(bm, m), min(bn, n), min(bk, k)
     fm_p = _pad_to(_pad_to(fm, 0, bm_), 1, bk_)
-    wk_p = _pad_to(wk, 0, bk_)
+    wk_p = _pad_to(wk, 1, bk_)
     gm_p = _pad_to(_pad_to(gm, 0, bk_), 1, bn_)
     out = quadconv_matmul(fm_p, wk_p, gm_p, bm=bm_, bn=bn_, bk=bk_,
                           interpret=(mode == "interpret"))
@@ -75,7 +75,7 @@ def _fwd(f, w, g, mode, bm, bn, bk):
     if mode == "ref":
         return _ref.quadconv_contract(f, w, g), (f, w, g)
     fm = f.reshape(b, i * c)
-    wk = jnp.repeat(w, c)
+    wk = jnp.repeat(w, c)[None, :]
     gm = g.transpose(1, 3, 0, 2).reshape(i * c, j * o)
     out = _contract_gemm(fm, wk, gm, mode, bm, bn, bk)
     return out.reshape(b, j, o), (f, w, g)

@@ -2,20 +2,24 @@
 
 Three kernels, all bounded-memory (no ``[n, capacity]`` materialization):
 
-* ``probe`` — key lookup: one grid step per query block keeps the whole
-  (tiny) slot-metadata vectors in VMEM and folds capacity in ``blk_c``
-  chunks with a running min-slot accumulator; the transient match tile is
-  ``[blk_q, blk_c]``, independent of n and capacity.
-* ``sample`` — valid-slot selection: cumulative valid count over the slot
-  metadata (VPU cumsum), then the same blocked fold counts
-  ``Σ_j [cum_j <= r]`` — a branch-free binary-search equivalent.
+* ``probe`` — key lookup on a ``(query block, capacity block)`` grid.
+  Queries ride the sublanes as a ``[blk_q, 1]`` column, slot keys and
+  versions the lanes as ``[1, blk_c]`` rows, so the match tile
+  ``[blk_q, blk_c]`` is a plain 2-D broadcast; the output block stays
+  resident across the capacity axis and keeps a running min-slot.
+* ``sample`` — valid-slot selection.  The cumulative valid count is one
+  XLA ``cumsum`` over slot metadata (O(capacity)); the kernel then counts
+  ``Σ_j [cum_j <= r]`` over the same blocked grid — a branch-free
+  binary-search equivalent.
 * ``gather`` — the slab row fetch: scalar-prefetched slot indices drive
   the input ``BlockSpec`` index map, so each grid step DMAs exactly one
-  slab row HBM→VMEM→out (the idiomatic TPU gather; the slab never passes
-  through an intermediate).
+  slab row HBM→VMEM→out.  The row is blocked as the element's own
+  trailing 2-D shape (``[*lead, last]`` folded to ``[a, b]``), which is
+  always a legal TPU block and needs no relayout of the slab.
 
-On CPU the kernels run under ``interpret=True`` (parity tests); ``ops.py``
-selects the execution mode and handles padding to block multiples.
+Every block obeys the TPU tiling rule: its last two dims are multiples of
+(8, 128) or equal to the array's.  On CPU the kernels run under
+``interpret=True`` (parity tests); ``ops.py`` selects the execution mode.
 """
 
 from __future__ import annotations
@@ -30,15 +34,37 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["probe", "sample", "gather", "gather_sharded"]
 
-# numpy scalar: inlined as a literal rather than captured as a traced const
-_EMPTY = np.uint32(0xFFFFFFFF)
+# EMPTY_KEY (0xFFFFFFFF) bitcast to int32: keys are compared as int32 so
+# the kernel needs no unsigned vector ops.
+_EMPTY_I32 = np.int32(-1)
+_I32_MAX = np.int32(np.iinfo(np.int32).max)
+# (query block, capacity block) grid: queries are independent, the
+# capacity axis accumulates into the resident output block.
+_REDUCE_GRID = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))
 
 
-def _pad1(x, mult, fill):
-    pad = (-x.shape[0]) % mult
+def _round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
+def _pad1(x, size, fill):
+    pad = size - x.shape[0]
     if pad:
         x = jnp.concatenate([x, jnp.full((pad,), fill, x.dtype)])
     return x
+
+
+def _blocks(n: int, capacity: int, blk_q: int, blk_c: int):
+    """Effective (blk_q, n_pad, blk_c, cap_pad): query blocks are a
+    multiple of 8 sublanes, capacity blocks a multiple of 128 lanes."""
+    bq = min(blk_q, _round_up(max(n, 1), 8))
+    bc = min(blk_c, _round_up(max(capacity, 1), 128))
+    return bq, _round_up(max(n, 1), bq), bc, _round_up(max(capacity, 1), bc)
+
+
+def _as_i32(x):
+    return jax.lax.bitcast_convert_type(x.astype(jnp.uint32), jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -46,23 +72,20 @@ def _pad1(x, mult, fill):
 # ---------------------------------------------------------------------------
 
 def _probe_kernel(keys_ref, ver_ref, query_ref, idx_ref, *, blk_c: int,
-                  n_c: int, capacity: int):
-    q = query_ref[0, :]                                   # [blk_q] uint32
-    blk_q = q.shape[0]
+                  capacity: int):
+    c = pl.program_id(1)
 
-    def fold(c, best):
-        ks = keys_ref[0, pl.ds(c * blk_c, blk_c)]          # [blk_c]
-        vs = ver_ref[0, pl.ds(c * blk_c, blk_c)]
-        match = (q[:, None] == ks[None, :]) & (vs > 0)[None, :] \
-            & (q != _EMPTY)[:, None]                       # [blk_q, blk_c]
-        slot = c * blk_c + jax.lax.broadcasted_iota(
-            jnp.int32, (blk_q, blk_c), 1)
-        cand = jnp.where(match, slot, capacity)
-        return jnp.minimum(best, jnp.min(cand, axis=1))
+    @pl.when(c == 0)
+    def _init():
+        idx_ref[...] = jnp.full(idx_ref.shape, capacity, jnp.int32)
 
-    best = jax.lax.fori_loop(
-        0, n_c, fold, jnp.full((blk_q,), capacity, jnp.int32))
-    idx_ref[0, :] = best
+    q = query_ref[...]                                   # [blk_q, 1]
+    match = (q == keys_ref[...]) & (ver_ref[...] > 0) \
+        & (q != _EMPTY_I32)                              # [blk_q, blk_c]
+    slot = c * blk_c + jax.lax.broadcasted_iota(jnp.int32, match.shape, 1)
+    cand = jnp.where(match, slot, capacity)
+    idx_ref[...] = jnp.minimum(idx_ref[...],
+                               jnp.min(cand, axis=1, keepdims=True))
 
 
 @functools.partial(jax.jit, static_argnames=("blk_q", "blk_c", "interpret"))
@@ -71,73 +94,81 @@ def probe(table_keys: jax.Array, version: jax.Array, query: jax.Array,
     """keys u32[C], version i32[C], query u32[n] → idx i32[n] (C = absent)."""
     capacity = table_keys.shape[0]
     n = query.shape[0]
-    keys_p = _pad1(table_keys.astype(jnp.uint32), blk_c, _EMPTY)[None, :]
-    ver_p = _pad1(version.astype(jnp.int32), blk_c, 0)[None, :]
-    q_p = _pad1(query.astype(jnp.uint32), blk_q, _EMPTY)
-    g = q_p.shape[0] // blk_q
-    n_c = keys_p.shape[1] // blk_c
+    bq, n_p, bc, c_p = _blocks(n, capacity, blk_q, blk_c)
+    keys_p = _pad1(_as_i32(table_keys), c_p, _EMPTY_I32)[None, :]
+    ver_p = _pad1(version.astype(jnp.int32), c_p, 0)[None, :]
+    q_p = _pad1(_as_i32(query), n_p, _EMPTY_I32)[:, None]
     idx = pl.pallas_call(
-        functools.partial(_probe_kernel, blk_c=blk_c, n_c=n_c,
-                          capacity=capacity),
-        grid=(g,),
+        functools.partial(_probe_kernel, blk_c=bc, capacity=capacity),
+        grid=(n_p // bq, c_p // bc),
         in_specs=[
-            pl.BlockSpec((1, keys_p.shape[1]), lambda i: (0, 0)),
-            pl.BlockSpec((1, ver_p.shape[1]), lambda i: (0, 0)),
-            pl.BlockSpec((1, blk_q), lambda i: (i, 0)),
+            pl.BlockSpec((1, bc), lambda i, c: (0, c)),
+            pl.BlockSpec((1, bc), lambda i, c: (0, c)),
+            pl.BlockSpec((bq, 1), lambda i, c: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, blk_q), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g, blk_q), jnp.int32),
+        out_specs=pl.BlockSpec((bq, 1), lambda i, c: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_p, 1), jnp.int32),
+        compiler_params=_REDUCE_GRID,
         interpret=interpret,
-    )(keys_p, ver_p, q_p.reshape(g, blk_q))
-    return idx.reshape(-1)[:n]
+    )(keys_p, ver_p, q_p)
+    return idx[:n, 0]
 
 
 # ---------------------------------------------------------------------------
 # sample: slot of the r-th valid entry
 # ---------------------------------------------------------------------------
 
-def _sample_kernel(ver_ref, r_ref, out_ref, *, blk_c: int, n_c: int):
-    valid = (ver_ref[...] > 0).astype(jnp.int32)           # [1, Cp]
-    cum = jnp.cumsum(valid, axis=1)                        # [1, Cp]
-    r = r_ref[0, :]                                        # [blk_q]
-    blk_q = r.shape[0]
+def _sample_kernel(cum_ref, r_ref, out_ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        out_ref[...] = jnp.zeros(out_ref.shape, jnp.int32)
 
-    def fold(c, acc):
-        cc = jax.lax.dynamic_slice(cum, (0, c * blk_c), (1, blk_c))[0]
-        tile = (cc[None, :] <= r[:, None]).astype(jnp.int32)
-        return acc + jnp.sum(tile, axis=1)
-
-    out_ref[0, :] = jax.lax.fori_loop(
-        0, n_c, fold, jnp.zeros((blk_q,), jnp.int32))
+    tile = (cum_ref[...] <= r_ref[...]).astype(jnp.int32)  # [blk_q, blk_c]
+    out_ref[...] += jnp.sum(tile, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("blk_q", "blk_c", "interpret"))
 def sample(version: jax.Array, ranks: jax.Array, blk_q: int = 128,
            blk_c: int = 128, interpret: bool = False):
     """version i32[C], ranks i32[n] → slots i32[n] (r-th valid slot)."""
+    capacity = version.shape[0]
     n = ranks.shape[0]
-    ver_p = _pad1(version.astype(jnp.int32), blk_c, 0)[None, :]
-    # Padded rank lanes get -1 → slot 0; they are sliced off below.
-    r_p = _pad1(ranks.astype(jnp.int32), blk_q, -1)
-    g = r_p.shape[0] // blk_q
-    n_c = ver_p.shape[1] // blk_c
+    bq, n_p, bc, c_p = _blocks(n, capacity, blk_q, blk_c)
+    cum = jnp.cumsum((version > 0).astype(jnp.int32))
+    # Padded slots never count (cum = INT32_MAX > any rank); padded rank
+    # lanes get -1 → slot 0 and are sliced off below.
+    cum_p = _pad1(cum, c_p, _I32_MAX)[None, :]
+    r_p = _pad1(ranks.astype(jnp.int32), n_p, -1)[:, None]
     slots = pl.pallas_call(
-        functools.partial(_sample_kernel, blk_c=blk_c, n_c=n_c),
-        grid=(g,),
+        _sample_kernel,
+        grid=(n_p // bq, c_p // bc),
         in_specs=[
-            pl.BlockSpec((1, ver_p.shape[1]), lambda i: (0, 0)),
-            pl.BlockSpec((1, blk_q), lambda i: (i, 0)),
+            pl.BlockSpec((1, bc), lambda i, c: (0, c)),
+            pl.BlockSpec((bq, 1), lambda i, c: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, blk_q), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g, blk_q), jnp.int32),
+        out_specs=pl.BlockSpec((bq, 1), lambda i, c: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_p, 1), jnp.int32),
+        compiler_params=_REDUCE_GRID,
         interpret=interpret,
-    )(ver_p, r_p.reshape(g, blk_q))
-    return slots.reshape(-1)[:n]
+    )(cum_p, r_p)
+    return slots[:n, 0]
 
 
 # ---------------------------------------------------------------------------
 # gather: slab row fetch via scalar-prefetched indices
 # ---------------------------------------------------------------------------
+
+def _row_view(slab: jax.Array):
+    """``[C, *elem]`` → ``[C, a, b]``: the element's leading dims fold
+    into ``a`` (a free reshape of major dims), its last dim is ``b``.  A
+    ``(None, a, b)`` block then equals the array in its last two dims."""
+    elem = slab.shape[1:]
+    b = elem[-1] if elem else 1
+    a = 1
+    for d in elem[:-1]:
+        a *= d
+    return slab.reshape(slab.shape[0], a, b)
+
 
 def _gather_kernel(idx_ref, slab_ref, out_ref):
     del idx_ref  # consumed by the BlockSpec index maps
@@ -157,27 +188,22 @@ def _gather_sharded_kernel(meta_ref, slab_ref, out_ref, *, local_cap: int):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather(slab: jax.Array, slots: jax.Array, interpret: bool = False):
     """slab [C, *elem], slots i32[n] (in-range) → rows [n, *elem]."""
-    capacity = slab.shape[0]
     elem = slab.shape[1:]
     n = slots.shape[0]
-    feat = 1
-    for d in elem:
-        feat *= d
-    slab2 = slab.reshape(capacity, max(feat, 1))
+    rows3 = _row_view(slab)
+    blk = (None, *rows3.shape[1:])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
-        in_specs=[pl.BlockSpec((1, slab2.shape[1]),
-                               lambda i, idx_ref: (idx_ref[i], 0))],
-        out_specs=pl.BlockSpec((1, slab2.shape[1]),
-                               lambda i, idx_ref: (i, 0)),
+        in_specs=[pl.BlockSpec(blk, lambda i, idx_ref: (idx_ref[i], 0, 0))],
+        out_specs=pl.BlockSpec(blk, lambda i, idx_ref: (i, 0, 0)),
     )
     rows = pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, slab2.shape[1]), slab.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, *rows3.shape[1:]), slab.dtype),
         interpret=interpret,
-    )(slots.astype(jnp.int32), slab2)
+    )(slots.astype(jnp.int32), rows3)
     return rows.reshape((n, *elem))
 
 
@@ -199,10 +225,8 @@ def gather_sharded(local_slab: jax.Array, slots: jax.Array, offset,
     local_cap = local_slab.shape[0]
     elem = local_slab.shape[1:]
     n = slots.shape[0]
-    feat = 1
-    for d in elem:
-        feat *= d
-    slab2 = local_slab.reshape(local_cap, max(feat, 1))
+    rows3 = _row_view(local_slab)
+    blk = (None, *rows3.shape[1:])
     meta = jnp.concatenate([
         jnp.asarray(offset, jnp.int32).reshape(1),
         slots.astype(jnp.int32)])
@@ -210,15 +234,15 @@ def gather_sharded(local_slab: jax.Array, slots: jax.Array, offset,
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[pl.BlockSpec(
-            (1, slab2.shape[1]),
-            lambda i, m: (jnp.clip(m[i + 1] - m[0], 0, local_cap - 1), 0))],
-        out_specs=pl.BlockSpec((1, slab2.shape[1]),
-                               lambda i, m: (i, 0)),
+            blk,
+            lambda i, m: (jnp.clip(m[i + 1] - m[0], 0, local_cap - 1), 0, 0))],
+        out_specs=pl.BlockSpec(blk, lambda i, m: (i, 0, 0)),
     )
     rows = pl.pallas_call(
         functools.partial(_gather_sharded_kernel, local_cap=local_cap),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, slab2.shape[1]), local_slab.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, *rows3.shape[1:]),
+                                       local_slab.dtype),
         interpret=interpret,
-    )(meta, slab2)
+    )(meta, rows3)
     return rows.reshape((n, *elem))

@@ -20,7 +20,9 @@ the producer side and the fused one-dispatch epoch on the consumer side.
 from __future__ import annotations
 
 import argparse
+import sys
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +36,11 @@ from ..ml import autoencoder as ae
 from ..ml import trainer as tr
 from ..sim import flatplate as fp
 from ..sim import spectral as sp
+from .cache import configure_compile_cache
+
+#: the repository checkout (``src/repro/launch`` → root): the fixed home
+#: of the compile cache when ``JAX_COMPILATION_CACHE_DIR`` is unset.
+CHECKOUT = Path(__file__).resolve().parents[3]
 
 
 def make_producer(*, sim_steps: int, producer: str, fcfg, ncfg,
@@ -104,8 +111,7 @@ def run(epochs: int = 40, sim_steps: int = 200, points: str = "small",
     ncfg = sp.NSConfig(n=16, nu=0.02, dt=0.01, forcing=True)
 
     cfg = tr.TrainerConfig(
-        ae=ae.AEConfig(n_points=n_points, latent=latent, mlp_width=16,
-                       mode="ref"),
+        ae=ae.AEConfig(n_points=n_points, latent=latent, mlp_width=16),
         epochs=epochs, gather=gather, batch_size=4, lr=lr,
         # paper-comparison runs (emulated solver cost) measure the
         # per-verb consumer so "retrieve" means what Table 2 means
@@ -161,7 +167,9 @@ def run(epochs: int = 40, sim_steps: int = 200, points: str = "small",
     return res
 
 
-def main() -> None:
+def main() -> int:
+    """CLI entry: run the session, return non-zero if any component
+    failed (its traceback is printed)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--epochs", type=int, default=40)
     ap.add_argument("--sim-steps", type=int, default=200)
@@ -173,10 +181,16 @@ def main() -> None:
     ap.add_argument("--consumers", type=int, default=1,
                     help="trainer replicas on disjoint mesh slices")
     args = ap.parse_args()
-    run(epochs=args.epochs, sim_steps=args.sim_steps,
-        producer=args.producer, points=args.points,
-        producers=args.producers, consumers=args.consumers)
+    configure_compile_cache(CHECKOUT)
+    res = run(epochs=args.epochs, sim_steps=args.sim_steps,
+              producer=args.producer, points=args.points,
+              producers=args.producers, consumers=args.consumers)
+    failed = [c for c in res.run.components.values() if not c.ok]
+    for comp in failed:
+        print(f"\ncomponent {comp.name!r} failed ({comp.error_type}):\n"
+              f"{comp.error}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

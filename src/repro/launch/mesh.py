@@ -8,7 +8,7 @@ tests/benches must keep seeing the single real device.
 
 from __future__ import annotations
 
-import jax
+from ..parallel.sharding import auto_mesh
 
 __all__ = ["make_production_mesh", "make_mesh_for", "HW"]
 
@@ -25,19 +25,10 @@ HW = {
 }
 
 
-def axis_types_kw(n: int) -> dict:
-    """``axis_types=(Auto,)*n`` kwargs only where this jax version has
-    ``jax.sharding.AxisType`` (older versions default to auto anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (axis_type.Auto,) * n} if axis_type is not None \
-        else {}
-
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_types_kw(len(axes)))
+    return auto_mesh(shape, axes)
 
 
 def make_mesh_for(n_devices: int, model_axis: int = 1, name_data: str = "data",
@@ -45,6 +36,5 @@ def make_mesh_for(n_devices: int, model_axis: int = 1, name_data: str = "data",
     """Small helper for laptop-scale runs/tests: (n/model, model) mesh."""
     if n_devices % model_axis:
         raise ValueError(f"{n_devices} devices, model axis {model_axis}")
-    return jax.make_mesh(
-        (n_devices // model_axis, model_axis), (name_data, name_model),
-        **axis_types_kw(2))
+    return auto_mesh((n_devices // model_axis, model_axis),
+                     (name_data, name_model))

@@ -49,7 +49,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..core import store as S
 from ..core.client import Client
@@ -410,7 +410,7 @@ def make_sharded_fused_epoch(cfg: TrainerConfig, levels,
     sharded = shard_map(epoch_body, mesh=mesh,
                         in_specs=(table_specs, P(), P(), P(), P()),
                         out_specs=(P(), P()),
-                        check_rep=False)
+                        check_vma=False)
     return jax.jit(sharded)
 
 
@@ -519,7 +519,7 @@ def make_clustered_sharded_epoch(cfg: TrainerConfig, levels,
     train_fn = jax.jit(shard_map(train_body, mesh=mesh,
                                  in_specs=(P(),) * 6,
                                  out_specs=(P(), P()),
-                                 check_rep=False))
+                                 check_vma=False))
 
     def epoch(client: Client, state: TrainState, rng, mu, sd):
         k_samp = jax.random.split(rng, 3)[0]

@@ -25,7 +25,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ["quantize_int8", "dequantize_int8", "ErrorFeedback",
            "compressed_psum_mean", "compressed_psum_mean_ef",
@@ -156,7 +156,7 @@ def compressed_allreduce(grad_stack: Any, mesh: Mesh, axis: str = "data",
 
         fn = shard_map(_worker, mesh=mesh,
                        in_specs=(P(axis),), out_specs=P(),
-                       check_rep=False)
+                       check_vma=False)
         return fn(g_stack)
 
     return jax.tree.map(_one, grad_stack)

@@ -28,7 +28,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ["pipeline_forward", "split_stages", "bubble_fraction"]
 
@@ -92,5 +92,5 @@ def pipeline_forward(stage_fn: Callable[[Any, jax.Array], jax.Array],
         _worker, mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return fn(stage_params, x_micro)

@@ -35,12 +35,12 @@ from typing import Any, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["DEFAULT_RULES", "use_mesh", "current_mesh", "spec_for", "shard",
            "sharding_for", "fitted_sharding", "logical_sharding", "ParamSpec",
            "init_params", "param_specs_to_shardings", "param_axes",
-           "data_mesh", "space_mesh", "disjoint_data_meshes",
+           "auto_mesh", "data_mesh", "space_mesh", "disjoint_data_meshes",
            "slab_sharding"]
 
 # logical axis -> mesh axis name(s)
@@ -176,6 +176,19 @@ def shard_fit(x: jax.Array, *axes: str | None) -> jax.Array:
 # DDP helpers (the sharded fused epoch's mesh plumbing)
 # ---------------------------------------------------------------------------
 
+def auto_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    ``jax.make_mesh`` defaults to Explicit axes, under which a store put
+    mixing a mesh-sharded slab with an unsharded update raises
+    ``ShardingTypeError``.  Every mesh in the repo is built here (or with
+    ``jax.sharding.Mesh``, which defaults to Auto) so GSPMD decides the
+    layouts.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 def data_mesh(n_devices: int | None = None, axis: str = "data") -> Mesh:
     """A 1-D mesh over ``axis`` for pure data parallelism.
 
@@ -185,9 +198,8 @@ def data_mesh(n_devices: int | None = None, axis: str = "data") -> Mesh:
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before the
     first jax call.
     """
-    from ..launch.mesh import axis_types_kw
     n = len(jax.devices()) if n_devices is None else int(n_devices)
-    return jax.make_mesh((n,), (axis,), **axis_types_kw(1))
+    return auto_mesh((n,), (axis,))
 
 
 def space_mesh(n_devices: int | None = None, axis: str = "space") -> Mesh:
@@ -200,9 +212,8 @@ def space_mesh(n_devices: int | None = None, axis: str = "space") -> Mesh:
     the db mesh's element axis (``core.deployment.make_clustered_2d``)
     when staging across meshes.
     """
-    from ..launch.mesh import axis_types_kw
     n = len(jax.devices()) if n_devices is None else int(n_devices)
-    return jax.make_mesh((n,), (axis,), **axis_types_kw(1))
+    return auto_mesh((n,), (axis,))
 
 
 def slab_sharding(spec, mesh: Mesh | None, axis: str = "data"

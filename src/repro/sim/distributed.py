@@ -216,7 +216,7 @@ def make_step(cfg: FDConfig, mesh: Mesh | None = None,
         return jax.jit(lambda state: _advance(cfg, state, exchange))
 
     cfg.validate_shards(int(mesh.shape[axis]), axis)
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def exchange(f, width):
         return halo_exchange(f, axis=axis, width=width, dim=0,
@@ -225,7 +225,7 @@ def make_step(cfg: FDConfig, mesh: Mesh | None = None,
     specs = FDState(u=P(axis, None), v=P(axis, None), t=P(), step=P())
     body = shard_map(lambda state: _advance(cfg, state, exchange),
                      mesh=mesh, in_specs=(specs,), out_specs=specs,
-                     check_rep=False)
+                     check_vma=False)
     return jax.jit(body)
 
 

@@ -22,6 +22,7 @@ from conftest import run_subprocess
 from repro.core import (Client, Clustered, Colocated, StoreServer,
                         TableSpec, make_clustered_1d, split_devices)
 from repro.core import store as S
+from repro.parallel.sharding import data_mesh
 
 SPEC = TableSpec("t", shape=(3,), capacity=4, engine="ring")
 
@@ -200,7 +201,7 @@ class TestStagedTelemetry:
         assert after["op_count"] == before["op_count"] + 1
 
     def test_colocated_and_local_never_stage(self):
-        for dep in (None, Colocated(jax.make_mesh((1,), ("data",)))):
+        for dep in (None, Colocated(data_mesh(1))):
             srv = StoreServer(dep)
             srv.create_table(TableSpec("t", shape=(3,), capacity=8))
             srv.put("t", S.make_key(0, 0), jnp.ones((3,)))

@@ -7,8 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.launch.mesh import axis_types_kw
 from repro.parallel import sharding as shd
+from repro.parallel.sharding import auto_mesh
 from repro.parallel.compress import (ErrorFeedback, dequantize_int8,
                                      quantize_int8)
 
@@ -17,18 +17,18 @@ from conftest import run_subprocess
 
 class TestShardingRules:
     def test_spec_for_filters_missing_axes(self):
-        mesh = jax.make_mesh((1,), ("data",), **axis_types_kw(1))
+        mesh = auto_mesh((1,), ("data",))
         spec = shd.spec_for(("batch", "heads"), mesh)
         assert tuple(spec) == ("data", None)       # no pod/model in mesh
 
     def test_no_axis_reuse(self):
-        mesh = jax.make_mesh((1,), ("data",), **axis_types_kw(1))
+        mesh = auto_mesh((1,), ("data",))
         spec = shd.spec_for(("batch", "embed"), mesh)   # both want "data"
         used = [s for s in tuple(spec) if s is not None]
         assert len(used) == len(set(used)) <= 1
 
     def test_fitted_sharding_keeps_divisible(self):
-        mesh = jax.make_mesh((1,), ("model",), **axis_types_kw(1))
+        mesh = auto_mesh((1,), ("model",))
         sh = shd.fitted_sharding(mesh, (7,), ("vocab",))
         assert tuple(sh.spec) == ("model",)     # 7 % 1 == 0
         # non-divisible drop is exercised at 16-way in the dry-run tests
@@ -50,9 +50,9 @@ def test_pipeline_matches_sequential():
     """2-stage GPipe over ppermute == plain sequential stack (fwd + grads)."""
     run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.launch.mesh import axis_types_kw
+        from repro.parallel.sharding import auto_mesh
         from repro.parallel.pipeline import pipeline_forward, split_stages
-        mesh = jax.make_mesh((2,), ("pod",), **axis_types_kw(1))
+        mesh = auto_mesh((2,), ("pod",))
         P_layers, D, M, mb = 4, 8, 4, 2
         key = jax.random.key(0)
         w = jax.random.normal(key, (P_layers, D, D)) * (0.5 / D**0.5)
@@ -105,8 +105,8 @@ def test_colocated_put_has_zero_collectives():
         from repro.core import store as S
         from repro.core.store import TableSpec
         from repro.analysis.hlo import collective_bytes, count_ops
-        from repro.launch.mesh import axis_types_kw
-        mesh = jax.make_mesh((8,), ("data",), **axis_types_kw(1))
+        from repro.parallel.sharding import auto_mesh
+        mesh = auto_mesh((8,), ("data",))
         spec = TableSpec("f", shape=(64, 128), capacity=4, engine="ring")
         slab_sh = NamedSharding(mesh, P(None, "data", None))
         elem_sh = NamedSharding(mesh, P("data", None))
@@ -145,8 +145,8 @@ def test_colocated_fused_put_path_collective_free():
         from repro.core import store as S
         from repro.core.store import TableSpec
         from repro.analysis.hlo import assert_collective_free
-        from repro.launch.mesh import axis_types_kw
-        mesh = jax.make_mesh((8,), ("data",), **axis_types_kw(1))
+        from repro.parallel.sharding import auto_mesh
+        mesh = auto_mesh((8,), ("data",))
         spec = TableSpec("f", shape=(64, 128), capacity=4, engine="ring")
         slab_sh = NamedSharding(mesh, P(None, "data", None))
         state = S.init_table(spec, slab_sh)
@@ -251,9 +251,9 @@ def test_slab_sharded_epoch_no_table_allgather():
 def test_compressed_allreduce_matches_mean():
     run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.launch.mesh import axis_types_kw
+        from repro.parallel.sharding import auto_mesh
         from repro.parallel.compress import compressed_allreduce
-        mesh = jax.make_mesh((4,), ("data",), **axis_types_kw(1))
+        mesh = auto_mesh((4,), ("data",))
         g = jax.random.normal(jax.random.key(0), (4, 33))   # 4 ranks
         out = compressed_allreduce({"w": g}, mesh, axis="data")["w"]
         ref = g.mean(0)

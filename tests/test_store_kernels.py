@@ -112,6 +112,34 @@ def test_sample_empty_table(mode):
     np.testing.assert_allclose(np.asarray(vals), 0.0)
 
 
+@pytest.mark.parametrize("capacity,n", [(256, 300), (300, 7)])
+def test_multi_block_grid_parity(capacity, n):
+    """Tables and query batches larger than one (128-lane, 128-row)
+    block: the capacity axis accumulates across grid steps and padded
+    slots/queries never leak into results."""
+    from repro.kernels.store import ops as kops
+    rng = np.random.default_rng(capacity + n)
+    keys = rng.integers(0, 2 * capacity, capacity).astype(np.uint32)
+    keys[rng.random(capacity) < 0.1] = S.EMPTY_KEY
+    ver = (rng.random(capacity) < 0.7) * rng.integers(1, 9, capacity)
+    keys, ver = jnp.asarray(keys), jnp.asarray(ver, jnp.int32)
+    query = rng.integers(0, 3 * capacity, n).astype(np.uint32)
+    query[0] = S.EMPTY_KEY
+    probes = [kops.probe_slots(keys, ver, jnp.asarray(query), m)
+              for m in MODES]
+    for a, b in zip(*probes):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    nvalid = int((ver > 0).sum())
+    ranks = jnp.asarray(np.concatenate(
+        [rng.integers(0, nvalid, n), [nvalid, capacity + 3]]), jnp.int32)
+    slots = [kops.sample_slots(ver, ranks, m) for m in MODES]
+    np.testing.assert_array_equal(np.asarray(slots[0]), np.asarray(slots[1]))
+    slab = jnp.asarray(rng.normal(size=(capacity, 4, 33)), jnp.float32)
+    safe = jnp.minimum(slots[0], capacity - 1)
+    rows = [kops.gather_rows(slab, safe, m) for m in MODES]
+    np.testing.assert_array_equal(np.asarray(rows[0]), np.asarray(rows[1]))
+
+
 # ---------------------------------------------------------------------------
 # Sharded gather (the slab-sharded data plane's shard-local fetch)
 # ---------------------------------------------------------------------------

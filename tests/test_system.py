@@ -160,3 +160,27 @@ def test_in_memory_checkpoint_restart():
     step, restored = got
     assert step == 7
     np.testing.assert_allclose(np.asarray(restored["w"]), [0, 1, 2])
+
+
+@pytest.mark.parametrize("failed", [False, True])
+def test_launcher_exit_code_reports_component_failure(monkeypatch, capsys,
+                                                      failed):
+    """``python -m repro.launch.insitu`` exits non-zero exactly when a
+    component recorded an error (the orchestrator isolates component
+    exceptions, so the session itself returns normally)."""
+    from types import SimpleNamespace
+
+    from repro.core.orchestrator import ComponentResult
+    from repro.launch import insitu
+
+    comps = {"producer": ComponentResult(name="producer"),
+             "trainer": ComponentResult(name="trainer")}
+    if failed:
+        comps["trainer"].error = "Traceback: boom"
+        comps["trainer"].error_type = "RuntimeError"
+    monkeypatch.setattr(insitu, "run", lambda **kw: SimpleNamespace(
+        run=SimpleNamespace(components=comps)))
+    monkeypatch.setattr(insitu, "configure_compile_cache", lambda root: "")
+    monkeypatch.setattr("sys.argv", ["insitu"])
+    assert insitu.main() == (1 if failed else 0)
+    assert ("'trainer' failed" in capsys.readouterr().err) == failed
