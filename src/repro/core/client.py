@@ -32,7 +32,7 @@ from . import store as S
 from .deployment import StagingPipeline
 from .faults import StoreTimeout, TransferDropped, call_with_retry
 from .server import StoreServer
-from .telemetry import Timers, poll_backoff
+from .telemetry import Timers, poll_backoff, span
 
 __all__ = ["Client"]
 
@@ -395,7 +395,9 @@ class Client:
         absorbed *before* the table lock is taken, so a failed attempt
         dispatches nothing and bumps no counters — the retried capture is
         the one that counts.  ``body(txn)``'s return value is passed
-        through (the fused trainer's ``(state, metrics)``)."""
+        through (the fused trainer's ``(state, metrics)``).  The call,
+        from entry to the dispatch's return, is the span
+        ``repro.capture_epoch``."""
         inj = self.server.faults
 
         def attempt():
@@ -404,9 +406,10 @@ class Client:
             with self.server.capture(table) as txn:
                 return body(txn)
 
-        if inj is None:
-            return attempt()
-        return call_with_retry(attempt, inj.retry, self._count_retry)
+        with span("capture_epoch"):
+            if inj is None:
+                return attempt()
+            return call_with_retry(attempt, inj.retry, self._count_retry)
 
     def latest_batch(self, table: str, n: int):
         with self.timers.time("retrieve") as box:

@@ -10,11 +10,12 @@ not for the ML consumer.
 
 Concurrency model (fused-pipeline rework):
 
-* **Per-table locks.** Every table owns its own ``RLock``; a producer
-  streaming into one table never serializes against a consumer reading a
-  different table.  The server-wide lock only guards the registries
-  (table/model/metadata maps), taken briefly and never while dispatching
-  table ops.
+* **Per-table locks.** Every table owns its own re-entrant lock; a
+  producer streaming into one table never serializes against a consumer
+  reading a different table.  Each wait for a table lock is the profiler
+  span ``repro.store.lock`` (``telemetry.SpanLock``).  The server-wide
+  lock only guards the registries (table/model/metadata maps), taken
+  briefly and never while dispatching table ops.
 * **Lock-free cached watermark.** A host-side monotonic counter per table
   is bumped at *dispatch* time (put +1, put_many +n, commit +puts), so
   ``watermark()`` / ``wait_watermark()`` read a Python int instead of
@@ -49,7 +50,7 @@ from . import store as S
 from .deployment import Colocated, Deployment
 from .faults import (FaultInjector, FaultPlan, StoreTimeout,
                      WatermarkTimeout)
-from .telemetry import Timers, poll_backoff
+from .telemetry import SpanLock, Timers, poll_backoff
 
 __all__ = ["StoreServer", "CaptureTxn", "PendingChunk"]
 
@@ -102,7 +103,7 @@ class StoreServer:
         self.deployment = deployment
         self.timers = timers or Timers()
         self._lock = threading.RLock()           # registries + metadata only
-        self._table_locks: dict[str, threading.RLock] = {}
+        self._table_locks: dict[str, SpanLock] = {}
         self._specs: dict[str, S.TableSpec] = {}
         self._state: dict[str, S.TableState] = {}
         self._counts: dict[str, int] = {}        # cached watermarks
@@ -160,7 +161,7 @@ class StoreServer:
                 raise ValueError(f"table {spec.name!r} already exists")
             self._specs[spec.name] = spec
             self._state[spec.name] = S.init_table(spec, slab_sharding)
-            self._table_locks[spec.name] = threading.RLock()
+            self._table_locks[spec.name] = SpanLock("store.lock")
             self._counts[spec.name] = 0
             self._placements[spec.name] = slab_sharding
             self._wal[spec.name] = []
@@ -181,7 +182,7 @@ class StoreServer:
     def hbm_bytes(self) -> int:
         return sum(S.table_bytes(sp) for sp in self._specs.values())
 
-    def table_lock(self, table: str) -> threading.RLock:
+    def table_lock(self, table: str) -> SpanLock:
         """The per-table lock (dispatch ordering for fused captures)."""
         return self._table_locks[table]
 

@@ -67,7 +67,7 @@ from __future__ import annotations
 
 import zlib
 from dataclasses import dataclass, replace
-from functools import partial
+from functools import partial, wraps
 from typing import Any, Callable, NamedTuple, Sequence
 
 import jax
@@ -111,6 +111,19 @@ __all__ = [
 
 KEY_DTYPE = jnp.uint32
 EMPTY_KEY = np.uint32(0xFFFFFFFF)
+#: Named scope of every put's operations in the compiled programs (and so
+#: in the profiler's device trace), wherever a put is traced.
+PUT_SCOPE = "store.put"
+
+
+def _put_scope(fn: Callable) -> Callable:
+    """Trace ``fn`` under ``jax.named_scope(PUT_SCOPE)``, a scope object of
+    its own per call (a scope object keeps state while entered)."""
+    @wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(PUT_SCOPE):
+            return fn(*args, **kwargs)
+    return scoped
 
 
 # ---------------------------------------------------------------------------
@@ -234,6 +247,7 @@ def _slot_for_put(spec: TableSpec, state: TableState, key) -> jax.Array:
 # public wrapper (the per-verb dispatch path).  ``spec`` is always static.
 # ---------------------------------------------------------------------------
 
+@_put_scope
 def put_impl(spec: TableSpec, state: TableState, key, value) -> TableState:
     """Insert/overwrite one element.  O(1) slab dynamic-update-slice."""
     value = jnp.asarray(value, dtype=spec.dtype)
@@ -257,6 +271,7 @@ def put_impl(spec: TableSpec, state: TableState, key, value) -> TableState:
 put = partial(jax.jit, static_argnums=0, donate_argnums=1)(put_impl)
 
 
+@_put_scope
 def put_many_impl(spec: TableSpec, state: TableState, keys, values) -> TableState:
     """Vectorized put of n elements (one producer step sending all ranks).
 
@@ -313,6 +328,7 @@ def put_many_impl(spec: TableSpec, state: TableState, keys, values) -> TableStat
 put_many = partial(jax.jit, static_argnums=0, donate_argnums=1)(put_many_impl)
 
 
+@_put_scope
 def put_masked_impl(spec: TableSpec, state: TableState, keys, values,
                     mask) -> TableState:
     """Vectorized put of the *masked subset* of a chunk, in chunk order.
@@ -381,6 +397,7 @@ put_masked = partial(jax.jit, static_argnums=0, donate_argnums=1)(
     put_masked_impl)
 
 
+@_put_scope
 def put_stream_impl(spec: TableSpec, state: TableState, keys, values
                     ) -> TableState:
     """Fold a whole trajectory of sends into one dispatch.

@@ -9,11 +9,16 @@ table.
 
 All timing helpers call ``jax.block_until_ready`` on the payload (when given)
 so async-dispatched device work is charged to the component that issued it.
+
+Every timed interval is also a span in the JAX profiler's trace
+(``span``: a ``jax.profiler.TraceAnnotation`` named ``repro.<name>``), so
+the component names sit on the device trace's clock.
 """
 
 from __future__ import annotations
 
 import math
+import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
@@ -21,10 +26,43 @@ from typing import Any
 
 import jax
 
-__all__ = ["Timers", "TimerStats", "poll_backoff"]
+__all__ = ["Timers", "TimerStats", "SpanLock", "poll_backoff", "span"]
+
+SPAN_PREFIX = "repro."
 
 
-def poll_backoff(timeout: float, interval: float, max_interval: float):
+def span(name: str, **args):
+    """A host span ``repro.<name>`` in the profiler's trace (a context
+    manager).  ``args`` become the span's stats; pass values already at
+    hand, since they are kept whether or not a profiler is running."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **args)
+
+
+class SpanLock:
+    """A re-entrant lock whose every acquisition is a span
+    ``repro.<name>`` over the wait for it, and only the wait."""
+
+    def __init__(self, name: str):
+        self._span = SPAN_PREFIX + name
+        self._lock = threading.RLock()
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        with jax.profiler.TraceAnnotation(self._span):
+            return self._lock.acquire(blocking, timeout)
+
+    def release(self) -> None:
+        self._lock.release()
+
+    def __enter__(self) -> "SpanLock":
+        self.acquire()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._lock.release()
+
+
+def poll_backoff(timeout: float, interval: float, max_interval: float,
+                 sleep_span: str | None = None):
     """Drive a deadline-bounded polling loop: yields once per probe,
     sleeping with exponential backoff (``interval`` doubling up to
     ``max_interval``) between probes, each sleep clamped to the time
@@ -36,6 +74,9 @@ def poll_backoff(timeout: float, interval: float, max_interval: float):
             if condition():
                 return True
         return condition()   # one last look at the deadline
+
+    ``sleep_span`` names a :func:`span` around each sleep (the probes
+    between sleeps stay outside it).
     """
     deadline = time.perf_counter() + timeout
     while True:
@@ -43,7 +84,11 @@ def poll_backoff(timeout: float, interval: float, max_interval: float):
         remaining = deadline - time.perf_counter()
         if remaining <= 0:
             return
-        time.sleep(min(interval, remaining))
+        if sleep_span is None:
+            time.sleep(min(interval, remaining))
+        else:
+            with span(sleep_span):
+                time.sleep(min(interval, remaining))
         interval = min(interval * 2.0, max_interval)
 
 
@@ -96,15 +141,19 @@ class Timers:
         The payload can also be supplied late by assigning to ``box[0]``
         of the yielded one-element list (useful when the timed block
         produces the arrays to block on).
+
+        The recorded interval, payload block included, is the span
+        ``repro.<name>``.
         """
         box = [payload]
-        t0 = time.perf_counter()
-        try:
-            yield box
-        finally:
-            if box[0] is not None:
-                jax.block_until_ready(box[0])
-            self.stats(name).add(time.perf_counter() - t0)
+        with span(name):
+            t0 = time.perf_counter()
+            try:
+                yield box
+            finally:
+                if box[0] is not None:
+                    jax.block_until_ready(box[0])
+                self.stats(name).add(time.perf_counter() - t0)
 
     def record(self, name: str, dt: float) -> None:
         self.stats(name).add(dt)

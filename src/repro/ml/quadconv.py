@@ -89,13 +89,15 @@ class QuadConv:
 
     def kernel_tensor(self, params: dict, coords_out: jax.Array,
                       coords_in: jax.Array) -> jax.Array:
-        """G[j,i,o,c] = MLP(x_j − y_i) ⊙ bump(|x_j − y_i|)."""
-        deltas = coords_out[:, None, :] - coords_in[None, :, :]   # [J,I,3]
-        j, i, _ = deltas.shape
-        g = mlp_apply(params["mlp"], deltas.reshape(j * i, 3))
-        g = g.reshape(j, i, self.c_out, self.c_in)
-        win = _bump(jnp.sum(deltas * deltas, -1), self.support)   # [J,I]
-        return g * win[:, :, None, None]
+        """G[j,i,o,c] = MLP(x_j − y_i) ⊙ bump(|x_j − y_i|), traced under the
+        named scope ``quadconv.kernel_tensor``."""
+        with jax.named_scope("quadconv.kernel_tensor"):
+            deltas = coords_out[:, None, :] - coords_in[None, :, :]  # [J,I,3]
+            j, i, _ = deltas.shape
+            g = mlp_apply(params["mlp"], deltas.reshape(j * i, 3))
+            g = g.reshape(j, i, self.c_out, self.c_in)
+            win = _bump(jnp.sum(deltas * deltas, -1), self.support)  # [J,I]
+            return g * win[:, :, None, None]
 
     def apply(self, params: dict, f: jax.Array, coords_in: jax.Array,
               coords_out: jax.Array) -> jax.Array:

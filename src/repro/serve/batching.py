@@ -33,11 +33,17 @@ class Request:
 
 
 class Batcher:
-    """Slot-based continuous batching over a fixed decode batch size."""
+    """Slot-based continuous batching over a fixed decode batch size.
 
-    def __init__(self, max_batch: int, eos_id: int | None = None):
+    Retired requests are appended to ``completed`` unless
+    ``keep_completed`` is False (a loop that lives as long as its server
+    and reads its answers elsewhere keeps none)."""
+
+    def __init__(self, max_batch: int, eos_id: int | None = None,
+                 keep_completed: bool = True):
         self.max_batch = max_batch
         self.eos_id = eos_id
+        self.keep_completed = keep_completed
         self.queue: deque[Request] = deque()
         self.slots: list[Request | None] = [None] * max_batch
         self._ids = itertools.count()
@@ -75,7 +81,8 @@ class Batcher:
                     len(req.tokens) >= req.max_new_tokens:
                 req.done = True
                 req.finished_at = now
-                self.completed.append(req)
+                if self.keep_completed:
+                    self.completed.append(req)
                 self.slots[i] = None
 
     @property

@@ -37,7 +37,7 @@ import numpy as np
 
 from ..core.client import Client
 from ..core.faults import StoreTimeout
-from ..core.telemetry import poll_backoff
+from ..core.telemetry import poll_backoff, span
 
 __all__ = ["ServeLoop", "request_key", "submitted_meta"]
 
@@ -86,7 +86,8 @@ class ServeLoop:
         self.component = component
         self.total = self.clients * self.requests
         from .batching import Batcher
-        self.batcher = Batcher(max_batch=self.max_batch)
+        self.batcher = Batcher(max_batch=self.max_batch,
+                               keep_completed=False)
         self._enqueued = [0] * self.clients   # next seq to discover, per client
         self._discovered: list[tuple[int, int]] = []  # admission order log
         self.served = 0                       # responses committed
@@ -151,8 +152,9 @@ class ServeLoop:
 
     def step(self) -> bool:
         """One drain iteration: swap check → discover → admit → ONE fused
-        serve dispatch over the active slots.  Returns False when no slot
-        was active (nothing discovered yet)."""
+        serve dispatch over the active slots (the span
+        ``repro.serve.dispatch``).  Returns False when no slot was active
+        (nothing discovered yet)."""
         if self._apply is None or self.batches % self.reload_every == 0:
             self.maybe_swap()
         self._discover()
@@ -167,8 +169,9 @@ class ServeLoop:
         if not mask.any():
             return False
         self.client.fault_point(self.component, self.batches)
-        self.client.serve_batch(self.request_table, self.response_table,
-                                keys, mask, self._apply, self._params)
+        with span("serve.dispatch"):
+            self.client.serve_batch(self.request_table, self.response_table,
+                                    keys, mask, self._apply, self._params)
         # max_new_tokens=1: one served token retires every active slot.
         self.batcher.record_tokens(np.zeros(self.max_batch, np.int64))
         self.batches += 1
@@ -179,7 +182,8 @@ class ServeLoop:
             timeout: float = 60.0) -> None:
         """Continuous-batching tier: drain until every request is
         answered.  Idle spins (queue empty, slots empty) back off without
-        dispatching; a full ``timeout`` of no progress raises."""
+        dispatching, each backoff sleep the span ``repro.serve.idle``; a
+        full ``timeout`` of no progress raises."""
         self.wait_model(timeout, stop_event)
         while self.served < self.total:
             if stop_event is not None and stop_event.is_set():
@@ -187,7 +191,8 @@ class ServeLoop:
             if self.step():
                 continue
             progressed = False
-            for _ in poll_backoff(timeout, 1e-4, 0.01):
+            for _ in poll_backoff(timeout, 1e-4, 0.01,
+                                  sleep_span="serve.idle"):
                 if self.step():
                     progressed = True
                     break
@@ -240,6 +245,7 @@ class ServeLoop:
         recovery never re-binds the model."""
         self.served = int(self.client.server.watermark(self.response_table))
         from .batching import Batcher
-        self.batcher = Batcher(max_batch=self.max_batch)
+        self.batcher = Batcher(max_batch=self.max_batch,
+                               keep_completed=False)
         for c, s in self._discovered[self.served:]:
             self.batcher.submit([c, s], max_new_tokens=1)
