@@ -47,9 +47,9 @@ import numpy as np  # noqa: E402
 
 #: flat-plate grid per rank (nx, ny, nz).  The paper's partition is
 #: 48x25x32 = 38,400 points; the dense QuadConv kernel tensor
-#: G[J,I,O,C] grows as N^2, and at 16x16x8 = 2,048 points the compiler
-#: refuses the train step (one 32 GiB buffer for G[2048,16,2048,16]),
-#: while 16x16x4 = 1,024 points compiles to 12.5 GiB of temporaries.
+#: G[J, O*C, I] grows as N^2: the fused train epoch compiles to 3.5 GiB
+#: of temporaries at 16x16x4 = 1,024 points and 14.1 GiB at 16x16x8 =
+#: 2,048 points, near a v5e's 16 GiB.
 GRID = (16, 16, 4)
 CAPACITY = 256          # ring slots: two 128-lane blocks for probe/sample
 SIM_STEPS = 128         # snapshots the producer captures (emit every step)
@@ -310,7 +310,8 @@ def one_chip() -> None:
     n = GRID[0] * GRID[1] * GRID[2]
     print(f"points per rank: {n} ({GRID[0]}x{GRID[1]}x{GRID[2]}), cut "
           f"from the paper's {paper.n_points}: the dense kernel tensor "
-          f"G[N,N,16,16] does not fit one chip at 2,048 points")
+          f"G[N,256,N] grows as N^2 (14.1 GiB of temporaries at 2,048 "
+          f"points)")
     session, cfg, fcfg = declare(make_colocated_1d())
     res = run_session(session, sequential=False, label="colocated-1")
     report_timers(res, "colocated-1")

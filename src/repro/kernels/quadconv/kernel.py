@@ -1,28 +1,43 @@
-"""Pallas TPU kernel for the QuadConv quadrature contraction.
+"""Pallas TPU kernels for the QuadConv quadrature contraction and its VJP.
 
-TPU adaptation (vs the paper's CUDA/PyTorch path): the contraction
+Layout.  The kernel tensor is ``G[J, R, I]`` with ``R = O·C`` (row
+``r = o·C + c``): output points major, the filter's features in the
+middle, input points minor.  It is the layout XLA gives the filter MLP
+when ``ml.quadconv.QuadConv.kernel_tensor`` evaluates it feature-major
+(a dot over ``[width, J, I]`` hidden states lands as ``[J][R][I]``), so
+``G`` is born in it, and every pass here reads or writes it in it:
 
-    out[b, j, o] = Σ_{i,c} w[i] · G[j,i,o,c] · f[b,i,c]
+* the input points fill the 128 lanes and the features (64 or 256) the
+  sublanes, so a block ``G[jb, :, ib]`` is dense; a layout with a 16-wide
+  channel axis last is padded to 128 lanes in HBM, 8× the bytes;
+* a block is one matrix ``G2[(j, r), i]`` of ``bj·R`` rows, so each pass
+  is one matmul per block with ``G2`` never transposed.
 
-is reshaped into a single GEMM  ``out[B, J·O] = F'[B, I·C] @ Gm[I·C, J·O]``
-with the quadrature weighting ``F' = f ⊙ w`` **fused into the LHS load** —
-so the weighted field is never materialized in HBM.  The kernel is a
-classic MXU-tiled matmul:
+The channel ``c`` of a row pairs with the channel of the features, so the
+small operand of each matmul carries all channels along its 128-wide
+side, ``(c', b)`` with the batch padded to ``Bp`` (a multiple of 8), and a
+mask keeps ``c' == c``:
 
-* grid = (B/bm, J·O/bn, I·C/bk); the K axis is innermost so each (m, n)
-  output tile stays resident in VMEM across the K loop (accumulate in
-  fp32), written once on the last K step.
-* block shapes default to (128, 128, 512): MXU-aligned 128-lane tiles;
-  VMEM footprint = bm·bk (F) + bk·bn (G) + bm·bn (acc) floats
-  = (128·512 + 512·128 + 128·128)·4B ≈ 0.6 MB ≪ 16 MB v5e VMEM,
-  leaving room for double buffering of the streamed G tiles.
-* ``w`` is pre-expanded to the flattened I·C axis by the ops wrapper and
-  passed as a ``[1, K]`` row (a ``[1, bk]`` block per K tile — a legal TPU
-  block whose layout matches XLA's), broadcast-multiplied into the F tile
-  on load — one VPU multiply per element, free next to the MXU work.
+* ``quadconv_matmul``, the forward: ``P[(j,r), (c',b)] = Σ_i G2 ·
+  fwx[i, (c',b)]`` with ``fwx = w ⊙ f``, masked to ``c' = c(r)`` and
+  summed over ``c'``: ``z[b, (j, r)] = Σ_i G[j,r,i] · w[i] f[b,i,c(r)]``.
+  ``ops.py`` sums ``z`` over the ``C`` rows of each output channel.
+  Grid ``(j-block | i-block)``.
+* ``quadconv_bwd_q``, the backward pass's one read of ``G``:
+  ``Q[(c,b), i] = Σ_{j,r} ct[b, j, o(r)] [c(r) = c] · G[j,r,i]``.
+  Grid ``(i-block | j-block)``.  ``ops.py`` takes ``df = w ⊙ Q`` and
+  ``dw = Σ_{b,c} Q ⊙ f`` from it.
+* ``quadconv_bwd_dg``, the backward pass's one write of ``dG``, in
+  ``G``'s layout: ``dG[j,r,i] = Σ_b ct[b, j, o(r)] · w[i] f[b,i,c(r)]``.
+  Every grid axis parallel.
 
-On CPU the kernel runs under ``interpret=True`` (tests); ``ops.py`` picks
-the execution mode.
+The cotangent comes in as ``ctr[b, (j, r)] = ct[b, j, o(r)]``, repeated
+over ``c`` (``ops.py``).  A block holds ``bj`` output points × ``R`` ×
+``bi`` input points; ``bj·R`` is a multiple of 128 and ``bi`` a multiple
+of 128 or all of ``I`` (``ops.blocks``).  Dots take float32 operands at
+the default precision and accumulate in float32; masks, transposes and
+the sums over ``c'`` are exact.  On CPU the kernels run under
+``interpret=True`` (tests).
 """
 
 from __future__ import annotations
@@ -34,64 +49,176 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["quadconv_matmul"]
+__all__ = ["quadconv_matmul", "quadconv_bwd_q", "quadconv_bwd_dg"]
+
+#: Scoped VMEM for every kernel: two 4 MiB blocks of ``G`` in flight plus
+#: the small operands and temporaries, within v5e's 128 MiB.
+VMEM_BYTES = 48 << 20
 
 
-def _kernel(f_ref, w_ref, g_ref, out_ref, acc_ref, *, n_k: int):
-    """One (m, n, k) grid step: acc += (F ⊙ w)[m, k] @ G[k, n]."""
-    k = pl.program_id(2)
+def _params(*semantics: str) -> pltpu.CompilerParams:
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=VMEM_BYTES)
 
-    @pl.when(k == 0)
+
+def _check(shape, bj, bi, c):
+    j, r, i = shape
+    if j % bj or i % bi or r % c or r % 8:
+        raise ValueError(f"G {shape} must divide into blocks ({bj},{r},{bi}) "
+                         f"of {c} channels; ops.py pads before calling")
+
+
+def _rows(g_ref):
+    """The block ``G[jb, :, ib]`` as one matrix ``[bj·R, bi]``, float32."""
+    bj, r, bi = g_ref.shape
+    return g_ref[...].reshape(bj * r, bi).astype(jnp.float32)
+
+
+def _spread(ctr, c: int, w: int):
+    """``[Bp, L]`` -> ``[W, L]``: row ``(c', b)`` holds ``ctr[b]`` where the
+    lane's channel ``c(r)`` is ``c'``, else 0."""
+    bp, length = ctr.shape
+    x = jnp.concatenate([ctr] * (w // bp), axis=0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (w, length), 0) // bp
+    col = jax.lax.broadcasted_iota(jnp.int32, (w, length), 1) % c
+    return jnp.where(row == col, x, 0.0)
+
+
+def _fwd_kernel(g_ref, fwx_ref, z_ref, acc_ref, *, c: int, bp: int):
+    ib = pl.program_id(1)
+
+    @pl.when(ib == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    f_blk = f_ref[...].astype(jnp.float32) * w_ref[...].astype(jnp.float32)
-    g_blk = g_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot_general(
-        f_blk, g_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += jnp.dot(_rows(g_ref), fwx_ref[...],
+                            preferred_element_type=jnp.float32)
 
-    @pl.when(k == n_k - 1)
+    @pl.when(ib == pl.num_programs(1) - 1)
     def _store():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+        p = acc_ref[...]                                   # [L, W]
+        length, w = p.shape
+        row = jax.lax.broadcasted_iota(jnp.int32, p.shape, 0) % c
+        col = jax.lax.broadcasted_iota(jnp.int32, p.shape, 1) // bp
+        y = jnp.where(row == col, p, 0.0).T                # [W, L]
+        z_ref[...] = y.reshape(w // bp, bp, length).sum(0).astype(z_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def quadconv_matmul(fm: jax.Array, wk: jax.Array, gm: jax.Array,
-                    bm: int = 128, bn: int = 128, bk: int = 512,
-                    interpret: bool = False) -> jax.Array:
-    """Fused quadrature-weighted GEMM.
+@functools.partial(jax.jit, static_argnames=("c", "bp", "bj", "bi",
+                                             "interpret"))
+def quadconv_matmul(g: jax.Array, fwx: jax.Array, *, c: int, bp: int,
+                    bj: int, bi: int, interpret: bool = False) -> jax.Array:
+    """Forward contraction in ``G``'s own layout.
 
     Args:
-      fm: [M, K]  flattened features (M = batch, K = I·C).
-      wk: [1, K]  quadrature weights pre-broadcast to the K axis.
-      gm: [K, N]  flattened kernel tensor (N = J·O).
+      g:   [J, R, I] kernel tensor, ``R = O·C``.
+      fwx: [I, W] weighted features, ``fwx[i, c·Bp + b] = w[i] f[b,i,c]``,
+           float32, zero beyond ``C·Bp``.
     Returns:
-      [M, N] = (fm ⊙ wk) @ gm
+      [Bp, J·R] float32:
+      ``z[b, j·R + r] = Σ_i g[j,r,i] fwx[i, c(r)·Bp + b]``.
     """
-    m, k = fm.shape
-    k2, n = gm.shape
-    assert k == k2 and wk.shape == (1, k), (fm.shape, wk.shape, gm.shape)
-    bm_, bn_, bk_ = min(bm, m), min(bn, n), min(bk, k)
-    if m % bm_ or n % bn_ or k % bk_:
-        raise ValueError(
-            f"shapes ({m},{n},{k}) must divide block ({bm_},{bn_},{bk_}); "
-            "ops.py pads before calling")
-    n_k = k // bk_
-    grid = (m // bm_, n // bn_, n_k)
+    j, r, i = g.shape
+    w = fwx.shape[1]
+    assert fwx.shape == (i, w) and w % bp == 0, (g.shape, fwx.shape, bp)
+    _check(g.shape, bj, bi, c)
+    length = bj * r
     return pl.pallas_call(
-        functools.partial(_kernel, n_k=n_k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm_, bk_), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((1, bk_), lambda i, j, kk: (0, kk)),
-            pl.BlockSpec((bk_, bn_), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), fm.dtype),
-        scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        functools.partial(_fwd_kernel, c=c, bp=bp),
+        grid=(j // bj, i // bi),
+        in_specs=[pl.BlockSpec((bj, r, bi), lambda jb, ib: (jb, 0, ib)),
+                  pl.BlockSpec((bi, w), lambda jb, ib: (ib, 0))],
+        out_specs=pl.BlockSpec((bp, length), lambda jb, ib: (0, jb)),
+        out_shape=jax.ShapeDtypeStruct((bp, j * r), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((length, w), jnp.float32)],
+        compiler_params=_params("parallel", "arbitrary"),
         interpret=interpret,
-    )(fm, wk, gm)
+    )(g, fwx)
+
+
+def _bwd_q_kernel(ctr_ref, g_ref, q_ref, acc_ref, *, c: int):
+    jb = pl.program_id(1)
+
+    @pl.when(jb == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    x = _spread(ctr_ref[...], c, acc_ref.shape[0])         # [W, L]
+    acc_ref[...] += jnp.dot(x, _rows(g_ref),
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(jb == pl.num_programs(1) - 1)
+    def _store():
+        q_ref[...] = acc_ref[...].astype(q_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("c", "w", "bj", "bi",
+                                             "interpret"))
+def quadconv_bwd_q(g: jax.Array, ctr: jax.Array, *, c: int, w: int,
+                   bj: int, bi: int, interpret: bool = False) -> jax.Array:
+    """The backward pass's read of ``G``.
+
+    Args:
+      g:   [J, R, I] kernel tensor.
+      ctr: [Bp, J·R] cotangent repeated over channels,
+           ``ctr[b, j·R + r] = ct[b, j, o(r)]``, float32.
+      w:   rows of the result, a multiple of 8 at least ``C·Bp``.
+    Returns:
+      [W, I] float32: ``Q[c·Bp + b, i] = Σ_{j, r: c(r)=c} ctr[b, j·R + r]
+      g[j,r,i]``, zero beyond ``C·Bp``.
+    """
+    j, r, i = g.shape
+    bp = ctr.shape[0]
+    assert ctr.shape == (bp, j * r), (g.shape, ctr.shape)
+    _check(g.shape, bj, bi, c)
+    return pl.pallas_call(
+        functools.partial(_bwd_q_kernel, c=c),
+        grid=(i // bi, j // bj),
+        in_specs=[pl.BlockSpec((bp, bj * r), lambda ib, jb: (0, jb)),
+                  pl.BlockSpec((bj, r, bi), lambda ib, jb: (jb, 0, ib))],
+        out_specs=pl.BlockSpec((w, bi), lambda ib, jb: (0, ib)),
+        out_shape=jax.ShapeDtypeStruct((w, i), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((w, bi), jnp.float32)],
+        compiler_params=_params("parallel", "arbitrary"),
+        interpret=interpret,
+    )(ctr, g)
+
+
+def _bwd_dg_kernel(ctr_ref, fwr_ref, dg_ref, *, c: int):
+    bj, r, bi = dg_ref.shape
+    x = _spread(ctr_ref[...], c, fwr_ref.shape[0]).T       # [L, W]
+    dg = jnp.dot(x, fwr_ref[...], preferred_element_type=jnp.float32)
+    dg_ref[...] = dg.reshape(bj, r, bi).astype(dg_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("c", "shape", "dtype", "bj",
+                                             "bi", "interpret"))
+def quadconv_bwd_dg(ctr: jax.Array, fwr: jax.Array, *, c: int, shape: tuple,
+                    dtype, bj: int, bi: int,
+                    interpret: bool = False) -> jax.Array:
+    """The backward pass's write of ``dG``, in ``G``'s layout.
+
+    Args:
+      ctr: [Bp, J·R] cotangent repeated over channels (``quadconv_bwd_q``).
+      fwr: [W, I] weighted features, ``fwr[c·Bp + b, i] = w[i] f[b,i,c]``,
+           float32, zero beyond ``C·Bp``.
+      shape, dtype: ``G``'s, ``(J, R, I)``.
+    Returns:
+      [J, R, I]: ``dG[j,r,i] = Σ_b ctr[b, j·R + r] fwr[c(r)·Bp + b, i]``.
+    """
+    j, r, i = shape
+    bp = ctr.shape[0]
+    w = fwr.shape[0]
+    assert ctr.shape == (bp, j * r) and fwr.shape == (w, i), \
+        (shape, ctr.shape, fwr.shape)
+    _check(shape, bj, bi, c)
+    return pl.pallas_call(
+        functools.partial(_bwd_dg_kernel, c=c),
+        grid=(j // bj, i // bi),
+        in_specs=[pl.BlockSpec((bp, bj * r), lambda jb, ib: (0, jb)),
+                  pl.BlockSpec((w, bi), lambda jb, ib: (0, ib))],
+        out_specs=pl.BlockSpec((bj, r, bi), lambda jb, ib: (jb, 0, ib)),
+        out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        compiler_params=_params("parallel", "parallel"),
+        interpret=interpret,
+    )(ctr, fwr)

@@ -28,7 +28,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from .quadconv import QuadConv, mlp_init, mlp_apply
+from .quadconv import QuadConv
 
 __all__ = ["AEConfig", "init_autoencoder", "encode", "decode", "reconstruct",
            "loss_fn", "rel_frobenius", "coords_pyramid", "compression_factor"]

@@ -11,8 +11,15 @@ Compact support is enforced with a smooth bump window so kernels stay local
 on the stretched boundary-layer grid.
 
 The pairwise contraction (the FLOPs hot spot) is delegated to
-``repro.kernels.quadconv`` (Pallas on TPU, oracle on CPU).  The MLP kernel
-evaluation over J×I offsets is a plain batched MLP and is left to XLA.
+``repro.kernels.quadconv``: Pallas kernels on TPU for the forward
+(``quadconv_matmul``) and the backward (``quadconv_bwd_q`` reads ``G``,
+``quadconv_bwd_dg`` writes ``dG``), the einsum oracle on CPU.  The kernel
+tensor's evaluation over J×I offsets is a plain MLP left to XLA.  It runs
+feature-major, hidden states ``[width, J, I]``, which XLA lays out
+``[J][width][I]``; so ``G`` is born as ``[J, O·C, I]`` (input points
+minor), the layout every pass of the contraction reads and writes.  No
+pass relays out the 1-GB-class ``G`` or its gradient, and no 16-wide
+channel axis is padded to 128 lanes (``kernels/quadconv/kernel.py``).
 
 Spectral normalization from the original QuadConv MLPs is omitted — the
 paper removes it "to ensure traceability for online inference"; we keep
@@ -49,8 +56,12 @@ def mlp_init(key, sizes: tuple[int, ...], scale: float = 1.0) -> list[dict]:
 
 
 def mlp_apply(params: list[dict], x: jax.Array) -> jax.Array:
+    """The MLP feature-major: ``x`` [fan_in, ...] -> [fan_out, ...].  Same
+    params as row-major (``w`` [fan_in, fan_out]), contracted on fan_in."""
+    bcast = (slice(None),) + (None,) * (x.ndim - 1)
     for i, layer in enumerate(params):
-        x = x @ layer["w"] + layer["b"]
+        x = jax.lax.dot_general(layer["w"], x, (((0,), (0,)), ((), ()))) \
+            + layer["b"][bcast]
         if i < len(params) - 1:
             x = jax.nn.gelu(x)
     return x
@@ -89,15 +100,18 @@ class QuadConv:
 
     def kernel_tensor(self, params: dict, coords_out: jax.Array,
                       coords_in: jax.Array) -> jax.Array:
-        """G[j,i,o,c] = MLP(x_j − y_i) ⊙ bump(|x_j − y_i|), traced under the
-        named scope ``quadconv.kernel_tensor``."""
+        """G[j, o·C + c, i] = MLP(x_j − y_i)[o,c] ⊙ bump(|x_j − y_i|),
+        ``[J, O·C, I]``, traced under the named scope
+        ``quadconv.kernel_tensor``.  The MLP runs feature-major over
+        ``[width, J, I]``; XLA lays that out ``[J][width][I]``, so the
+        final transpose is free and ``G`` is born in the contraction's
+        layout."""
         with jax.named_scope("quadconv.kernel_tensor"):
-            deltas = coords_out[:, None, :] - coords_in[None, :, :]  # [J,I,3]
-            j, i, _ = deltas.shape
-            g = mlp_apply(params["mlp"], deltas.reshape(j * i, 3))
-            g = g.reshape(j, i, self.c_out, self.c_in)
-            win = _bump(jnp.sum(deltas * deltas, -1), self.support)  # [J,I]
-            return g * win[:, :, None, None]
+            # [3, J, I]
+            deltas = coords_out.T[:, :, None] - coords_in.T[:, None, :]
+            g = mlp_apply(params["mlp"], deltas)                     # [OC,J,I]
+            win = _bump(jnp.sum(deltas * deltas, 0), self.support)  # [J,I]
+            return (g * win).transpose(1, 0, 2)
 
     def apply(self, params: dict, f: jax.Array, coords_in: jax.Array,
               coords_out: jax.Array) -> jax.Array:

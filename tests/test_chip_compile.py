@@ -7,7 +7,9 @@ with the TPU compiler, with interpret mode off.  The compiler refuses
 what interpret mode accepts — blocks that break the (8, 128) tiling
 rule, layouts Mosaic cannot cast — so these guard the chip path at no
 chip time.  Each asserts the kernel is in the compiled program
-(``tpu_custom_call``), i.e. no reference took its place.
+(``tpu_custom_call``), i.e. no reference took its place.  One more
+compiles a whole QuadConv layer's forward and backward and asserts that
+nothing in it relays out the kernel tensor ``G``.
 
 The topology is described only inside the module fixture: the TPU
 library may be loaded by one process at a time, and every test worker
@@ -17,6 +19,8 @@ imports this file.
 from __future__ import annotations
 
 import importlib.util
+import math
+import re
 from pathlib import Path
 
 import jax
@@ -27,6 +31,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import quadconv_ae
 from repro.kernels.quadconv import quadconv_contract
 from repro.kernels.store import kernel as K
+from repro.ml.quadconv import QuadConv
 
 _SMOKE = Path(__file__).resolve().parents[1] / "chip_smoke.py"
 
@@ -124,4 +129,35 @@ def test_quadconv_compiles(chip, points, c_in, c_out):
         lambda f, w, g: quadconv_contract(f, w, g, "pallas"),
         _arg(chip, (SMOKE.BATCH, points, c_in), jnp.float32),
         _arg(chip, (points,), jnp.float32),
-        _arg(chip, (points, points, c_out, c_in), jnp.float32))
+        _arg(chip, (points, c_out * c_in, points), jnp.float32))
+
+
+_RELAYOUT = re.compile(r"=\s*\w+\[([\d,]*)\]\S*\s+(copy|transpose|reshape|"
+                       r"copy-start)\(")
+
+
+def test_quadconv_layer_never_relays_out_g(chip):
+    """Forward and backward of one 1,024-point 16->16 QuadConv layer, the
+    kernel tensor built by its filter MLP included: the optimised program
+    holds no copy, transpose or reshape of as many elements as ``G``
+    (``bitcast`` is free and allowed), and the three kernels are in it."""
+    cfg = quadconv_ae.config()
+    points, c = N_POINTS, cfg.internal
+    conv = QuadConv(c_in=c, c_out=c, mlp_width=cfg.mlp_width,
+                    mlp_depth=cfg.mlp_depth, mode="pallas")
+    params = jax.tree.map(
+        lambda x: _arg(chip, x.shape, x.dtype),
+        jax.eval_shape(lambda: conv.init(jax.random.key(0), points)))
+
+    def loss(p, f, x):
+        return jnp.sum(jnp.square(conv.apply(p, f, x, x)))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, _arg(chip, (SMOKE.BATCH, points, c), jnp.float32),
+        _arg(chip, (points, 3), jnp.float32)).compile().as_text()
+    g_elems = points * points * c * c
+    relayouts = [line.strip()[:160] for line in text.splitlines()
+                 if (m := _RELAYOUT.search(line)) and math.prod(
+                     int(d) for d in m.group(1).split(",") if d) == g_elems]
+    assert not relayouts, relayouts
+    assert text.count('custom_call_target="tpu_custom_call"') == 3

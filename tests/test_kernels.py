@@ -11,7 +11,9 @@ import numpy as np
 import pytest
 from _hyp import given, settings, st  # optional-hypothesis shim
 
+from repro.kernels.quadconv import kernel as K, ops
 from repro.kernels.quadconv import quadconv_contract, quadconv_contract_ref
+from repro.ml.quadconv import QuadConv
 
 
 def _rand(key, *shape, dtype=jnp.float32):
@@ -19,6 +21,13 @@ def _rand(key, *shape, dtype=jnp.float32):
 
 
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
+
+
+def _g(key, J, I, O, C, dtype=jnp.float32):
+    """A kernel tensor drawn as [J, I, O, C], in the contraction's layout
+    [J, O*C, I]."""
+    return _rand(key, J, I, O, C, dtype=dtype).transpose(0, 2, 3, 1) \
+        .reshape(J, O * C, I)
 
 
 @pytest.mark.parametrize("B,I,C,J,O", [
@@ -32,9 +41,9 @@ def test_quadconv_kernel_sweep(B, I, C, J, O, dtype):
     ks = jax.random.split(jax.random.key(0), 3)
     f = _rand(ks[0], B, I, C, dtype=dtype)
     w = jax.random.uniform(ks[1], (I,)).astype(dtype)
-    g = _rand(ks[2], J, I, O, C, dtype=dtype)
+    g = _g(ks[2], J, I, O, C, dtype=dtype)
     ref = quadconv_contract_ref(f, w, g)
-    out = quadconv_contract(f, w, g, "interpret", 8, 128, 128)
+    out = quadconv_contract(f, w, g, "interpret")
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         atol=TOL[dtype], rtol=TOL[dtype])
@@ -47,9 +56,9 @@ def test_quadconv_kernel_property(B, I, C, J, O):
     ks = jax.random.split(jax.random.key(B * 1000 + I), 3)
     f = _rand(ks[0], B, I, C)
     w = jax.random.uniform(ks[1], (I,))
-    g = _rand(ks[2], J, I, O, C)
+    g = _g(ks[2], J, I, O, C)
     ref = quadconv_contract_ref(f, w, g)
-    out = quadconv_contract(f, w, g, "interpret", 8, 128, 128)
+    out = quadconv_contract(f, w, g, "interpret")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
 
@@ -57,7 +66,7 @@ def test_quadconv_kernel_grads_match_ref():
     ks = jax.random.split(jax.random.key(1), 3)
     f = _rand(ks[0], 2, 32, 4)
     w = jax.random.uniform(ks[1], (32,))
-    g = _rand(ks[2], 16, 32, 8, 4)
+    g = _g(ks[2], 16, 32, 8, 4)
 
     def loss(f, w, g, mode):
         return jnp.sum(quadconv_contract(f, w, g, mode) ** 2)
@@ -73,11 +82,110 @@ def test_quadconv_linearity():
     ks = jax.random.split(jax.random.key(2), 4)
     f1, f2 = _rand(ks[0], 2, 24, 4), _rand(ks[1], 2, 24, 4)
     w = jax.random.uniform(ks[2], (24,))
-    g = _rand(ks[3], 12, 24, 8, 4)
+    g = _g(ks[3], 12, 24, 8, 4)
     lhs = quadconv_contract(2.0 * f1 + 3.0 * f2, w, g, "interpret")
     rhs = 2.0 * quadconv_contract(f1, w, g, "interpret") \
         + 3.0 * quadconv_contract(f2, w, g, "interpret")
     np.testing.assert_allclose(np.asarray(lhs), np.asarray(rhs), atol=1e-4)
+
+
+# The autoencoder's layer shapes scaled down (points J = I; channels 4->16
+# and 16->16) and sizes that pad: the batch to 8, the rows O*C to a
+# multiple of 8, the input points to whole blocks of 128.
+QC_LAYERS = [
+    pytest.param(4, 256, 4, 16, id="enc0-4to16"),
+    pytest.param(4, 128, 16, 16, id="16to16"),
+    pytest.param(1, 64, 16, 16, id="16to16-batch1"),
+    pytest.param(3, 40, 3, 5, id="ragged-rows"),
+]
+
+
+def _layer(B, P, C, O, key=5):
+    ks = jax.random.split(jax.random.key(key), 4)
+    return (_rand(ks[0], B, P, C), jax.random.uniform(ks[1], (P,)),
+            _g(ks[2], P, P, O, C), _rand(ks[3], B, P, O))
+
+
+@pytest.mark.parametrize("B,P,C,O", QC_LAYERS)
+def test_quadconv_vjp_matches_oracle(B, P, C, O):
+    """Forward, df, dw and dG of the kernels against autodiff of the
+    einsum oracle, for one cotangent."""
+    f, w, g, ct = _layer(B, P, C, O)
+    outs = {}
+    for mode in ("ref", "interpret"):
+        out, vjp = jax.vjp(lambda f, w, g, m=mode: quadconv_contract(
+            f, w, g, m), f, w, g)
+        outs[mode] = (out, *vjp(ct))
+    for name, a, b in zip(("out", "df", "dw", "dG"), outs["ref"],
+                          outs["interpret"]):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-4,
+                                   rtol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("B,P,C,O,bj,bi", [
+    pytest.param(2, 256, 16, 16, 4, 128, id="16to16-multiblock"),
+    pytest.param(3, 256, 4, 16, 2, 128, id="enc0-multiblock"),
+])
+def test_quadconv_kernels_over_many_blocks(B, P, C, O, bj, bi):
+    """Each kernel accumulates or tiles over several blocks on both points
+    axes, with blocks smaller than the entry point would choose."""
+    f, w, g, ct = _layer(B, P, C, O, key=6)
+    bp, width = 8, 128
+    fw = jnp.pad((f * w[:, None]).transpose(2, 0, 1), ((0, 0), (0, bp - B),
+                                                      (0, 0)))
+    fwr = jnp.pad(fw.reshape(C * bp, P), ((0, width - C * bp), (0, 0)))
+    ctr = jnp.pad(jnp.repeat(ct, C, axis=-1), ((0, bp - B), (0, 0), (0, 0)))
+    ctr = ctr.reshape(bp, P * O * C)
+    kw = dict(bj=bj, bi=bi, interpret=True)
+    z = K.quadconv_matmul(g, fwr.T, c=C, bp=bp, **kw)
+    out = z.reshape(bp, P, O, C).sum(-1)[:B]
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(quadconv_contract_ref(f, w, g)),
+                               atol=1e-4)
+    _, vjp = jax.vjp(quadconv_contract_ref, f, w, g)
+    _, _, dg_ref = vjp(ct)
+    q = K.quadconv_bwd_q(g, ctr, c=C, w=width, **kw)
+    q = q[:C * bp].reshape(C, bp, P)[:, :B].transpose(1, 2, 0)
+    np.testing.assert_allclose(
+        np.asarray(q * w[:, None]), np.asarray(vjp(ct)[0]), atol=1e-4)
+    dg = K.quadconv_bwd_dg(ctr, fwr, c=C, shape=g.shape, dtype=g.dtype, **kw)
+    np.testing.assert_allclose(np.asarray(dg), np.asarray(dg_ref), atol=1e-4)
+
+
+def test_quadconv_blocks_follow_the_shapes():
+    """Whole rows of input points, then output points up to 4 MiB of G,
+    with every block tiling-legal: the autoencoder's layers (1,024 and 256
+    points, 16 and 4 input channels) and a layer too wide for one row."""
+    assert ops.blocks(1024, 256, 1024) == (4, 1024)
+    assert ops.blocks(1024, 64, 1024) == (16, 1024)
+    assert ops.blocks(256, 256, 256) == (16, 256)
+    assert ops.blocks(8192, 256, 8192) == (1, 4096)
+    for j, r, i in [(1024, 256, 1024), (300, 64, 5000), (17, 15, 50)]:
+        bj, bi = ops.blocks(j, r, i)
+        assert bj * r * bi <= ops.BLOCK_ELEMS or bj == 1
+        assert (bj * r) % 128 == 0 or bj == j
+        assert bi == i or bi % 128 == 0
+
+
+def test_quadconv_grad_through_kernel_tensor_matches_ref():
+    """jax.grad of a loss through the filter MLP's kernel tensor and the
+    contraction: the kernels' custom VJP against autodiff of the oracle."""
+    conv_k = QuadConv(c_in=4, c_out=8, mlp_width=16, mlp_depth=3,
+                      mode="interpret")
+    conv_r = QuadConv(c_in=4, c_out=8, mlp_width=16, mlp_depth=3, mode="ref")
+    ks = jax.random.split(jax.random.key(7), 3)
+    params = conv_k.init(ks[0], 48)
+    coords = jax.random.uniform(ks[1], (48, 3))
+    f = _rand(ks[2], 2, 48, 4)
+
+    def loss(p, f, conv):
+        return jnp.sum(jnp.square(conv.apply(p, f, coords, coords)))
+
+    gk = jax.grad(loss, argnums=(0, 1))(params, f, conv_k)
+    gr = jax.grad(loss, argnums=(0, 1))(params, f, conv_r)
+    for a, b in zip(jax.tree.leaves(gk), jax.tree.leaves(gr)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-4)
 
 
 # ---------------------------------------------------------------------------
